@@ -387,7 +387,9 @@ class BatchedVidpf:
         same byte-exact outputs, but the per-eval intermediates never
         round-trip HBM (PERF.md §3's roofline lever).  Unsupported
         shapes (tiny batches, huge-payload converts, binders past one
-        sponge block) keep the scan path."""
+        sponge block) keep the scan path.  The fused form does not
+        compile for a v5e yet (tests/test_tpu_compile.py): on a TPU the
+        lever raises the compiler's error at the first level step."""
         (_seed_cw, _ctrl_cw, _w_cw, proof_cw) = cw_slice
         (num_reports, num_parents) = parents.ctrl.shape
 

@@ -3,9 +3,9 @@ item 4, the compiler-first refactor).
 
 Steady-state rounds pay zero inline compile since r9, but every
 *process* still pays the full trace+XLA bill before its first round
-(`BENCH_LAST_GOOD.json`: 100.8 s on the incremental round) — exactly
-the cold start the r11 collector service eats on restart or tenant
-admission.  This module lowers the round-program family ahead of time
+(100.8 s on the incremental round in the r5 chip session, PERF.md
+§5) — exactly the cold start the r11 collector service eats on
+restart or tenant admission.  This module lowers the round-program family ahead of time
 to serialized artifacts a fresh process loads in seconds:
 
 * **what is stored** — every `ProgramCache` entry kind ("eval" /
@@ -402,7 +402,15 @@ class ArtifactStore:
         if hashlib.sha256(payload).hexdigest() != entry["sha256"]:
             return (None, CORRUPT)
         try:
-            loaded = se.deserialize_and_load(*pickle.loads(payload))
+            # The executable's own devices (the first `devices` of the
+            # host, as the runners' meshes take them): without them a
+            # single-device program loads expecting one shard per
+            # local device and fails its probe on any multi-device
+            # host.
+            loaded = se.deserialize_and_load(
+                *pickle.loads(payload),
+                execution_devices=jax.devices()[:int(
+                    entry.get("devices", 1))])
         except Exception:
             return (None, CORRUPT)
         # Gate (c): the bit-identity probe round — the loaded

@@ -51,6 +51,22 @@ def _device_budget() -> int:
                               DEVICE_BUDGET_DEFAULT))
 
 
+def carries_donated() -> bool:
+    """Whether the round programs donate their carry inputs.  They do
+    unless this process's persistent compile cache is on: JAX
+    deserializes a cached executable on a hit, and a deserialized
+    executable that donates its inputs double-frees them
+    (RoundPrograms._donation_safe)."""
+    return not jax.config.jax_compilation_cache_dir
+
+
+def carry_copies() -> int:
+    """Copies of the carries a round holds at once: with donation the
+    eval program writes its output carries over its inputs; without,
+    inputs and outputs are live side by side."""
+    return 1 if carries_donated() else 2
+
+
 def _host_budget() -> int:
     env = os.environ.get("MASTIC_HOST_BUDGET_BYTES")
     if env is not None:
@@ -144,17 +160,25 @@ def memory_envelope(bm: BatchedMastic, chunk_size: int, width: int,
     per-device budget actually bounds; tests/test_mesh_pipeline.py
     locks them against real per-device allocations).  Device rows pad
     up to the shard multiple first (uneven chunks shard by padding +
-    masking, not by uneven placement — jax refuses the latter)."""
+    masking, not by uneven placement — jax refuses the latter).
+
+    The `device_bytes_*` fields are what stays allocated between
+    rounds.  A round holds more: without carry donation
+    (`carry_copies`) its output carries sit beside its inputs, and
+    the `*_round_*`, `*_peak_*` and `max_*` fields price that copy."""
     per = per_report_bytes(bm, width)
     per_chunk = per["carry"] + per["roundkeys"] + per["store"]
+    copies = carry_copies()
+    outputs = (copies - 1) * per["carry"]
+    per_round = per_chunk + outputs
     shards = max(1, n_device_shards)
     dev_rows = -(-chunk_size // shards) * shards
     rows_per_shard = dev_rows // shards
-    # Worst-case round peak: resident state + binder staging with
+    # Worst-case round peak: what a round holds + binder staging with
     # every carried depth at full width.  Informational for planning
     # (real runs prune far below it) — the gating that protects a run
     # is per-round at the ACTUAL bucket, check_round_peak below.
-    per_peak = per_chunk + per["binder_peak"]
+    per_peak = per_round + per["binder_peak"]
     device_budget = _device_budget()
     host_budget = _host_budget()
     # Carries and round keys are allocated per padded chunk row (the
@@ -163,27 +187,31 @@ def memory_envelope(bm: BatchedMastic, chunk_size: int, width: int,
     padded_rows = -(-num_reports // chunk_size) * chunk_size
     host_total = (padded_rows * (per["carry"] + per["roundkeys"])
                   + num_reports * per["store"])
+    # Pipelined streaming keeps TWO chunks' resident state in flight
+    # (chunk i+1 uploads while chunk i computes/downloads;
+    # drivers/pipeline.py) — the output carries and the binder staging
+    # are paid once, only the chunk in its compute phase holds them.
+    # The executor degrades to serial when this footprint would exceed
+    # the budget (round_peak_bytes below, at the ACTUAL buckets).
+    per_pipelined = PIPELINE_CHUNKS_IN_FLIGHT * per_chunk + outputs
+
+    def fit(per_row: int) -> int:
+        return device_budget // per_row if device_budget > 0 else 0
+
     return {
         "bits": bm.vidpf.BITS, "width": width,
         "chunk_size": chunk_size, "num_reports": num_reports,
         "per_report_bytes": per,
+        "carry_copies": copies,
         "device_bytes_per_chunk": chunk_size * per_chunk,
+        "device_round_bytes_per_chunk": chunk_size * per_round,
         "device_peak_bytes_per_chunk": chunk_size * per_peak,
-        # Pipelined streaming keeps TWO chunks' resident state in
-        # flight (chunk i+1 uploads while chunk i computes/downloads;
-        # drivers/pipeline.py) — the binder staging is paid once, only
-        # the chunk in its compute phase holds it.  The executor
-        # degrades to serial when this doubled footprint would exceed
-        # the budget (round_peak_bytes below, at the ACTUAL buckets).
         "pipeline_chunks_in_flight": PIPELINE_CHUNKS_IN_FLIGHT,
         "device_bytes_per_chunk_pipelined":
             PIPELINE_CHUNKS_IN_FLIGHT * chunk_size * per_chunk,
         "device_peak_bytes_per_chunk_pipelined":
-            PIPELINE_CHUNKS_IN_FLIGHT * chunk_size * per_chunk
-            + chunk_size * per["binder_peak"],
-        "max_pipelined_chunk_size_at_width": (
-            device_budget // (PIPELINE_CHUNKS_IN_FLIGHT * per_chunk)
-            if device_budget > 0 else 0),
+            chunk_size * (per_pipelined + per["binder_peak"]),
+        "max_pipelined_chunk_size_at_width": fit(per_pipelined),
         # Per-shard residency: what ONE chip of the report-axis mesh
         # holds.  The padded device rows divide evenly by design, so
         # these are exact, not estimates.
@@ -191,25 +219,21 @@ def memory_envelope(bm: BatchedMastic, chunk_size: int, width: int,
         "device_rows_per_chunk": dev_rows,
         "rows_per_shard": rows_per_shard,
         "device_bytes_per_chunk_per_shard": rows_per_shard * per_chunk,
+        "device_round_bytes_per_chunk_per_shard":
+            rows_per_shard * per_round,
         "device_peak_bytes_per_chunk_per_shard":
             rows_per_shard * per_peak,
         "device_bytes_per_chunk_pipelined_per_shard":
             PIPELINE_CHUNKS_IN_FLIGHT * rows_per_shard * per_chunk,
         "device_peak_bytes_per_chunk_pipelined_per_shard":
-            PIPELINE_CHUNKS_IN_FLIGHT * rows_per_shard * per_chunk
-            + rows_per_shard * per["binder_peak"],
-        "max_chunk_size_at_width_sharded": (
-            shards * (device_budget // per_chunk)
-            if device_budget > 0 else 0),
-        "max_pipelined_chunk_size_at_width_sharded": (
-            shards * (device_budget
-                      // (PIPELINE_CHUNKS_IN_FLIGHT * per_chunk))
-            if device_budget > 0 else 0),
+            rows_per_shard * (per_pipelined + per["binder_peak"]),
+        "max_chunk_size_at_width_sharded": shards * fit(per_round),
+        "max_pipelined_chunk_size_at_width_sharded":
+            shards * fit(per_pipelined),
         "host_bytes_total": host_total,
         "device_budget_bytes": device_budget,
         "host_budget_bytes": host_budget,
-        "max_chunk_size_at_width": (device_budget // per_chunk
-                                    if device_budget > 0 else 0),
+        "max_chunk_size_at_width": fit(per_round),
         "min_hosts": (-(-host_total // host_budget)
                       if host_budget > 0 else 1),
     }
@@ -218,15 +242,14 @@ def memory_envelope(bm: BatchedMastic, chunk_size: int, width: int,
 def check_envelope(bm: BatchedMastic, chunk_size: int, width: int,
                    num_reports: int,
                    n_device_shards: int = 1) -> dict:
-    """Refuse shapes outside the envelope with an actionable message
-    (the guard VERDICT r4 asked for): the device check bounds one
-    chunk's live state — per chip when the chunk's report axis is
-    mesh-sharded over `n_device_shards` devices; the host check bounds
-    the carry store and names the multi-host answer when one host
-    cannot hold it."""
+    """Refuse shapes outside the envelope with an actionable message:
+    the device check bounds one chunk's live state — per chip when the
+    chunk's report axis is mesh-sharded over `n_device_shards`
+    devices; the host check bounds the carry store and names the
+    multi-host answer when one host cannot hold it."""
     env = memory_envelope(bm, chunk_size, width, num_reports,
                           n_device_shards)
-    per_chip = env["device_bytes_per_chunk_per_shard"]
+    per_chip = env["device_round_bytes_per_chunk_per_shard"]
     max_chunk = env["max_chunk_size_at_width_sharded"]
     if env["device_budget_bytes"] > 0 \
             and per_chip > env["device_budget_bytes"]:
@@ -267,24 +290,30 @@ def check_envelope(bm: BatchedMastic, chunk_size: int, width: int,
 def round_peak_bytes(bm: BatchedMastic, onehot_cap: int,
                      payload_cap: int, chunk_rows: int,
                      resident_bytes: int, n_device_shards: int = 1,
-                     chunks_in_flight: int = 1) -> int:
+                     chunks_in_flight: int = 1,
+                     carry_bytes: int = 0) -> int:
     """Per-chip peak of one round at the ACTUAL binder buckets:
     `chunks_in_flight` copies of the resident chunk state (the
     pipelined executor keeps two) plus ONE chunk's binder staging
-    (only the chunk in its compute phase holds the staging buffers).
+    (only the chunk in its compute phase holds the staging buffers),
+    plus, when the round programs do not donate their carries, that
+    chunk's output carries (`carry_bytes`: the carries' share of
+    `resident_bytes`; carry_copies).
     The single cost model behind check_round_peak (serial, raising)
     and the pipeline executor's degrade-to-serial decision
     (non-raising, drivers/chunked.ChunkedIncrementalRunner)."""
     staging = _binder_staging_bytes(bm, onehot_cap,
                                     payload_cap) * chunk_rows
-    return -(-(chunks_in_flight * resident_bytes + staging)
+    outputs = (carry_copies() - 1) * carry_bytes
+    return -(-(chunks_in_flight * resident_bytes + outputs + staging)
              // n_device_shards)
 
 
 def check_round_peak(bm: BatchedMastic, onehot_cap: int,
                      payload_cap: int, chunk_rows: int,
                      resident_bytes: int, level: int,
-                     n_device_shards: int = 1) -> None:
+                     n_device_shards: int = 1,
+                     carry_bytes: int = 0) -> None:
     """Per-round device-memory gate at the ACTUAL binder buckets.
 
     The construction-time envelope bounds resident state; the binder
@@ -306,20 +335,22 @@ def check_round_peak(bm: BatchedMastic, onehot_cap: int,
     per_row = _binder_staging_bytes(bm, onehot_cap, payload_cap)
     staging = per_row * chunk_rows
     peak = round_peak_bytes(bm, onehot_cap, payload_cap, chunk_rows,
-                            resident_bytes, n_device_shards)
+                            resident_bytes, n_device_shards,
+                            carry_bytes=carry_bytes)
     if peak > budget:
         # Largest TOTAL chunk size (across all its device shards)
         # whose peak fits: (resident_scaled + per_row*rows)/shards
         # <= budget, with resident scaling with rows too — bound it
         # conservatively by keeping resident's per-row share.
-        per_row_resident = resident_bytes // max(1, chunk_rows)
+        held = resident_bytes + (carry_copies() - 1) * carry_bytes
+        per_row_resident = held // max(1, chunk_rows)
         max_rows = max(0, (budget * n_device_shards)
                        // (per_row + per_row_resident))
         raise ValueError(
             f"level {level}: binder buckets {onehot_cap} (onehot) / "
             f"{payload_cap} (payload) need "
             f"{staging / 2**30:.1f} GiB of staging on top of "
-            f"{resident_bytes / 2**30:.1f} GiB resident "
+            f"{held / 2**30:.1f} GiB resident and output carries "
             f"({peak / 2**30:.1f} GiB peak per chip vs budget "
             f"{budget / 2**30:.1f} GiB) — checkpoint and resume with "
             f"a total chunk of <= {max_rows} reports (across its "
@@ -600,6 +631,11 @@ class ChunkedIncrementalRunner(RoundPrograms):
         acct = self.memory_accounting()["device_bytes_per_chunk"]
         return acct * self._device_rows() // self.store.chunk_size
 
+    def _carry_dev_bytes(self) -> int:
+        """The carries' share of `_resident_dev_bytes`."""
+        acct = self.memory_accounting()["device_carry_bytes"]
+        return acct * self._device_rows() // self.store.chunk_size
+
     def _pipeline_mode(self, plan) -> tuple:
         """(mode, fallback_reason): whether this round runs the
         double-buffered executor or degrades to serial — and why, so
@@ -619,7 +655,8 @@ class ChunkedIncrementalRunner(RoundPrograms):
                 len(plan.payload_parent), self._device_rows(),
                 self._resident_dev_bytes(),
                 self._report_shards(),
-                chunks_in_flight=PIPELINE_CHUNKS_IN_FLIGHT)
+                chunks_in_flight=PIPELINE_CHUNKS_IN_FLIGHT,
+                carry_bytes=self._carry_dev_bytes())
             if peak > budget:
                 return ("serial", "device-budget")
         return ("pipelined", None)
@@ -650,7 +687,8 @@ class ChunkedIncrementalRunner(RoundPrograms):
         check_round_peak(
             self.bm,
             len(plan.onehot_idx), len(plan.payload_parent),
-            dev_rows, self._resident_dev_bytes(), level, shards)
+            dev_rows, self._resident_dev_bytes(), level, shards,
+            self._carry_dev_bytes())
         (mode, fb_reason) = self._pipeline_mode(plan)
         rnd = round_inputs(plan)
         vk_arr = _vk_array(self.verify_key)

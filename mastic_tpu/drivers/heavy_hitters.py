@@ -14,7 +14,6 @@ present in the dict, else the default (reference examples.py:26-34,
 spec draft-mouris-cfrg-mastic.md:1535-1572).
 """
 
-import os
 import time
 from typing import Optional, Sequence
 
@@ -442,6 +441,9 @@ class HeavyHittersRun:
         self.prev_agg_params: list = []
         self.heavy_hitters: list = []
         self.metrics: list = []  # one RoundMetrics per completed level
+        # Each completed level's unsharded aggregate, one entry per
+        # candidate prefix in frontier order (not checkpointed).
+        self.aggregates: list = []
         self.profile_dir: Optional[str] = None  # jax.profiler target
         self.obs_tenant = ""     # telemetry label (set by the service)
         self.done = False
@@ -541,6 +543,7 @@ class HeavyHittersRun:
                                   tenant=self.obs_tenant)
         (level, _prefixes, _wc) = handle["agg_param"]
         self.prev_agg_params.append(handle["agg_param"])
+        self.aggregates.append(list(agg_result))
 
         survivors = [
             prefix for (prefix, count) in zip(self.prefixes, agg_result)
@@ -878,13 +881,14 @@ class RoundPrograms:
         from the warm shared cache corrupted the heap, and the FLP
         weight check then rejected every report (or segfaulted at
         teardown) while a cold-compiling child never failed.  So:
-        drop donation whenever the persistent cache is configured."""
-        if not self._donate_carries:
-            return False
-        cache_dir = (getattr(jax.config, "jax_compilation_cache_dir",
-                             None)
-                     or os.environ.get("JAX_COMPILATION_CACHE_DIR"))
-        return not cache_dir
+        drop donation whenever this process's persistent cache is on
+        (JAX reads JAX_COMPILATION_CACHE_DIR into this config at
+        import; entry points set it through mastic_tpu/compile_cache).
+        The memory model prices the second carry copy that costs
+        (chunked.carry_copies)."""
+        from .chunked import carries_donated
+
+        return self._donate_carries and carries_donated()
 
     def _eval_jit(self):
         if self._eval_fn is None:
@@ -1352,13 +1356,14 @@ class _IncrementalRunner(RoundPrograms):
 
         (level, prefixes, do_weight_check) = agg_param
         plan = self._plan(prefixes, level)
+        acct = self.memory_accounting()
         check_round_peak(
             self.bm,
             len(plan.onehot_idx), len(plan.payload_parent),
-            self.num_reports,
-            self.memory_accounting()["device_bytes_total"], level,
+            self.num_reports, acct["device_bytes_total"], level,
             (self.mesh.shape["reports"]
-             if self.mesh is not None else 1))
+             if self.mesh is not None else 1),
+            acct["device_carry_bytes"])
         from .pipeline import paused_gc
 
         t0 = time.perf_counter()

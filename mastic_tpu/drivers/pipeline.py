@@ -4,10 +4,10 @@ The chunked production path (PERF.md §4-5) streams fixed-size report
 chunks through one compiled round program.  Serially, each chunk pays
 the full upload -> compute -> download -> host chain with blocking
 `np.asarray` walls between every step, so the device idles during
-host work and the host idles during device work — BENCH_r05's
-`incremental_round` measured the production round at 211k evals/s on
-a chip whose kernel runs at 43.4M evals/s, with 100.8 s of inline
-XLA compile sitting on the critical path.  This module attacks both
+host work and the host idles during device work — the r5 chip
+session's `incremental_round` measured the production round at 211k
+evals/s on a chip whose kernel runs at 43.4M evals/s, with 100.8 s of
+inline XLA compile sitting on the critical path.  This module attacks both
 gaps:
 
 * **double-buffered chunk streaming** (`run_chunks`): chunk i+1's

@@ -12,12 +12,7 @@ the scalar CPU reference; batched TPU limb arithmetic lives in
 mastic_tpu/ops/field_jax.py.
 """
 
-from typing import TypeVar
-
-try:
-    from typing import Self
-except ImportError:  # Python < 3.11: the 3.10 fabric lacks typing.Self
-    from typing_extensions import Self
+from typing import Self, TypeVar
 
 from .common import from_le_bytes, to_le_bytes
 

@@ -124,6 +124,46 @@ def build_blob_pool(mastic, ctx: bytes, count: int, bits: int,
     return blobs
 
 
+def encode_upload_batch(bm, batch) -> list:
+    """Upload blobs for a device-sharded report batch
+    (`BatchedMastic.shard_device`): report r's blob is byte-identical
+    to `encode_upload(m, (nonce, public_share, input_shares))` of the
+    scalar `Mastic.shard` over the same nonce and rand — the client
+    fleet's wire traffic without a scalar shard per report.  Lanes
+    the shard flagged not-ok (XOF rejection) are the caller's to
+    re-shard through the scalar layer."""
+    from .. import wire
+
+    m = bm.m
+    num = int(batch.nonces.shape[0])
+
+    def u8(x):
+        return np.asarray(x, np.uint8).reshape(num, -1)
+
+    def field_bytes(limbs):
+        # 16-bit plain limbs, least significant first: their
+        # little-endian bytes are the field's wire encoding.
+        return np.ascontiguousarray(
+            np.asarray(limbs, np.uint32).astype("<u2")).view(
+                np.uint8).reshape(num, -1)
+
+    cws = batch.cws
+    ctrl = np.packbits(np.asarray(cws.ctrl, bool).reshape(num, -1),
+                       axis=1, bitorder="little")
+    public = [u8(batch.nonces), ctrl, u8(cws.seed), field_bytes(cws.w),
+              u8(cws.proof)]
+    keys = np.asarray(batch.keys, np.uint8)
+    leader = [keys[:, 0], field_bytes(batch.leader_proofs)]
+    helper = [keys[:, 1], u8(batch.helper_seeds)]
+    if m.flp.JOINT_RAND_LEN > 0:
+        leader += [u8(batch.leader_seeds), u8(batch.peer_parts[0])]
+        helper += [u8(batch.peer_parts[1])]
+    views = [np.concatenate(public + share, axis=1)
+             for share in (leader, helper)]
+    return [wire.frame(views[0][r].tobytes())
+            + wire.frame(views[1][r].tobytes()) for r in range(num)]
+
+
 def malform(blob: bytes, rng) -> bytes:
     """One adversarial variant of a valid blob: truncated mid-view or
     bit-flipped inside the first framed view — both decode-fail at

@@ -75,6 +75,33 @@ def test_save_load_round_trip_bit_identical(store):
         assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
+@pytest.mark.parametrize("mesh_n", [1, 2])
+def test_mesh_program_loads_on_multi_device_host(store, mesh_n):
+    """A program compiled over the first `mesh_n` devices of the
+    8-device test fabric (`--mesh 1` is the single-device case) loads
+    from a fresh store onto its own devices and passes the probe."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    assert len(jax.devices()) == 8
+    mesh = Mesh(np.asarray(jax.devices()[:mesh_n]), ("reports",))
+    sharding = NamedSharding(mesh, P("reports"))
+    fn = jax.jit(lambda a, b: (a + b, (a * b).sum()),
+                 out_shardings=(sharding, NamedSharding(mesh, P())))
+    args = tuple(jax.device_put(x, sharding)
+                 for x in (jnp.arange(8, dtype=jnp.uint32),
+                           jnp.full((8,), 3, jnp.uint32)))
+    compiled = fn.lower(*args).compile()
+    assert store.save(_key(), compiled)["devices"] == mesh_n
+    fresh = artifacts.ArtifactStore(store.path)
+    name = artifacts.key_name(_key())
+    (loaded, outcome) = fresh._gated_load(
+        name, fresh.manifest["entries"][name])
+    assert outcome == artifacts.HIT
+    for (a, b) in zip(jax.tree_util.tree_leaves(compiled(*args)),
+                      jax.tree_util.tree_leaves(loaded(*args))):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_miss_and_memoization(store):
     assert store.load(("absent", 1)) is None
     (_fn, _args, compiled) = _trivial()

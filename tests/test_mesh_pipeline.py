@@ -106,6 +106,51 @@ def test_envelope_per_shard_fields():
         env["device_bytes_per_chunk_per_shard"]
 
 
+def test_envelope_prices_output_carries_without_donation(monkeypatch):
+    """Without carry donation (the compile cache on) a round holds its
+    output carries beside its inputs: the round, peak and max terms
+    price that second copy, the resident terms stay what is
+    allocated, and the guards refuse on the round's footprint."""
+    from mastic_tpu.drivers import chunked
+
+    bm = BatchedMastic(MasticCount(3))
+    donated = memory_envelope(bm, 8, 8, 16, n_device_shards=2)
+    assert donated["carry_copies"] == 1
+    assert donated["device_round_bytes_per_chunk"] == \
+        donated["device_bytes_per_chunk"]
+    monkeypatch.setattr(chunked, "carries_donated", lambda: False)
+    env = memory_envelope(bm, 8, 8, 16, n_device_shards=2)
+    carry = env["per_report_bytes"]["carry"]
+    assert env["carry_copies"] == 2
+    assert env["device_bytes_per_chunk"] == \
+        donated["device_bytes_per_chunk"]
+    assert env["device_round_bytes_per_chunk"] == \
+        env["device_bytes_per_chunk"] + 8 * carry
+    assert env["device_round_bytes_per_chunk_per_shard"] == \
+        env["device_round_bytes_per_chunk"] // 2
+    assert env["device_peak_bytes_per_chunk"] == \
+        donated["device_peak_bytes_per_chunk"] + 8 * carry
+    assert env["device_peak_bytes_per_chunk_pipelined"] == \
+        donated["device_peak_bytes_per_chunk_pipelined"] + 8 * carry
+    per_round = env["device_round_bytes_per_chunk"] // 8
+    assert env["max_chunk_size_at_width"] == \
+        env["device_budget_bytes"] // per_round
+    assert env["max_chunk_size_at_width"] < \
+        donated["max_chunk_size_at_width"]
+    assert chunked.round_peak_bytes(bm, 4, 4, 8, 1000, 2,
+                                    carry_bytes=600) == \
+        chunked.round_peak_bytes(bm, 4, 4, 8, 1600, 2)
+    # A budget that holds the resident chunk but not the round's
+    # second carry copy is refused.
+    monkeypatch.setenv("MASTIC_DEVICE_BUDGET_BYTES",
+                       str(env["device_bytes_per_chunk_per_shard"]))
+    with pytest.raises(ValueError, match="feasible chunk_size"):
+        chunked.check_envelope(bm, 8, 8, 16, n_device_shards=2)
+    with pytest.raises(ValueError, match="output carries"):
+        chunked.check_round_peak(bm, 4, 4, 8, 16, 0, 2,
+                                 carry_bytes=2 ** 40)
+
+
 def test_pad_rows_rule_and_device_chunk():
     """Device-tile padding repeats row 0 (the host_slice rule), and
     the live mask excludes every padded lane — dead lanes compute the

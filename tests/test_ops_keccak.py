@@ -69,7 +69,7 @@ def test_keccak_pallas_chained_rounds_match_scan():
     equal the 12-round scan path.  This validates the multi-round
     state handoff and the ROUND_CONSTANTS start offset that the
     single kernel's unrolled form bakes in — without the >1 h
-    interpret compile of that form (VERDICT r4 ask #5)."""
+    interpret compile of that form."""
     pytest.importorskip("jax.experimental.pallas")
     import jax.numpy as jnp
 

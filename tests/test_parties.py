@@ -3,8 +3,8 @@
 The decoders must invert the conformance-locked encoders for every
 instantiation; the subprocess demo must reproduce a conformance
 vector's aggregate shares byte for byte with leader and helper as
-separate OS processes exchanging only wire bytes (VERDICT r2 item 6;
-reference wire types /root/reference/poc/mastic.py:31-49).
+separate OS processes exchanging only wire bytes (the wire types of
+the reference implementation's poc/mastic.py:31-49).
 """
 
 import json

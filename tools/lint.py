@@ -71,7 +71,7 @@ ANNOTATED = [
     "mastic_tpu/vidpf.py", "mastic_tpu/mastic.py", "mastic_tpu/vdaf.py",
     "mastic_tpu/oracle.py", "mastic_tpu/flp/flp.py",
     "mastic_tpu/flp/circuits.py", "mastic_tpu/testvec_codec.py",
-    "mastic_tpu/wire.py",
+    "mastic_tpu/wire.py", "mastic_tpu/compile_cache.py",
 ]
 
 PRINT_OK = ("tools/", "bench.py", "gen_test_vec.py", "tests/",

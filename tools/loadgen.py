@@ -354,6 +354,9 @@ def run_upload_drill(args, tmp: str) -> dict:
 
     serve_py = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                             "serve.py")
+    print("upload drill: children run with JAX_PLATFORMS=cpu "
+          "(they never touch the chip)", file=sys.stderr,
+          flush=True)
     bits = 2
     m = MasticCount(bits)
     rng = np.random.default_rng(args.replay + 30)
